@@ -23,6 +23,9 @@ import sys
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_strassen, bench_distgemm, bench_sort, bench_dag_overhead,
         bench_roofline, bench_serving)

@@ -1,15 +1,15 @@
-"""Paper Listing 1, both ways:
+"""Paper Listing 1, both ways, in one process:
 
 1. the Bind-model version on simulated nodes (implicit transfers, explicit
    log-reduction tree, execution stats), and
-2. the TPU lowering via shard_map on 8 fake devices (subprocess re-exec
-   with XLA_FLAGS), tree vs ring reduction schedules.
+2. the TPU lowering via shard_map over the devices that exist (a 1x1 mesh
+   on one device), tree vs ring reduction schedules.  The same lowering on
+   8 fake CPU devices is ``python -m repro.launch.selftest_distgemm``.
 
     PYTHONPATH=src python examples/distributed_gemm.py
 """
 
 import os
-import subprocess
 import sys
 
 import numpy as np
@@ -38,34 +38,26 @@ def bind_version() -> None:
 
 
 def shardmap_version() -> None:
-    if os.environ.get("_DISTGEMM_CHILD") != "1":
-        env = dict(os.environ, _DISTGEMM_CHILD="1")
-        env["PYTHONPATH"] = os.pathsep.join(
-            [os.path.join(os.path.dirname(__file__), "..", "src"),
-             env.get("PYTHONPATH", "")])
-        subprocess.run([sys.executable, __file__], check=True, env=env)
-        return
     import jax
     from repro.linalg.distributed import distributed_gemm_shardmap
 
     rng = np.random.default_rng(0)
     A = rng.normal(size=(64, 32)).astype(np.float32)
     B = rng.normal(size=(32, 48)).astype(np.float32)
-    mesh = jax.make_mesh((2, 4), ("p", "q"))
+    n = len(jax.devices())
+    q = 2 if n % 2 == 0 else 1
+    mesh = jax.make_mesh((n // q, q), ("p", "q"))
     for schedule in ("tree", "ring"):
         fn = distributed_gemm_shardmap(mesh, schedule=schedule)
-        out = np.asarray(fn(A, B))
+        # f32 passes on a TPU too (its default matmul precision is bf16)
+        with jax.default_matmul_precision("highest"):
+            out = np.asarray(fn(A, B))
         np.testing.assert_allclose(out, A @ B, rtol=2e-4, atol=2e-4)
-        print(f"[tpu lowering] (2,4) mesh, schedule={schedule}: OK")
+        print(f"[tpu lowering] {mesh.devices.shape} mesh, "
+              f"schedule={schedule}: OK")
 
 
 def main() -> None:
-    if os.environ.get("_DISTGEMM_CHILD") == "1":
-        os.environ["XLA_FLAGS"] = (
-            "--xla_force_host_platform_device_count=8 "
-            + os.environ.get("XLA_FLAGS", ""))
-        shardmap_version()
-        return
     bind_version()
     shardmap_version()
     print("OK")

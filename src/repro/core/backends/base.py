@@ -166,6 +166,14 @@ class Backend:
     def execute(self, ex, wf, plan) -> None:
         raise NotImplementedError
 
+    def place(self, ex, rank: int, payload):
+        """The payload to store for an initial array placed on ``rank``.
+
+        Simulated backends store it as given; the mesh backend puts it on
+        the rank's device while ranks map onto devices.
+        """
+        return payload
+
     def reset(self, ex) -> None:
         """Drop any backend-owned state tied to ``ex``'s current payloads.
 
@@ -343,8 +351,12 @@ def gather_args(ex, p, node) -> list:
         store0 = ex._stores[0]
         return [store0[k] if k is not None else a[1]
                 for k, a in zip(p.arg_keys, node.args)]
+    # the executing rank's own copy when it holds one: with payloads placed
+    # on devices (mesh backend), another rank's replica sits on another chip
     stores, where = ex._stores, ex._where
-    return [stores[next(iter(where[k]))][k] if k is not None else a[1]
+    local = stores[p.exec_ranks[0]]
+    return [(local[k] if k in local else stores[next(iter(where[k]))][k])
+            if k is not None else a[1]
             for k, a in zip(p.arg_keys, node.args)]
 
 

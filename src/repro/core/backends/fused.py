@@ -410,10 +410,14 @@ class FusedBatchBackend(Backend):
             result_nbytes[m] = nb
 
     # -- whole-chain fused dispatch -------------------------------------------
-    def _stored(self, ex, k):
-        """Resolve version ``k``'s payload from whichever rank holds it."""
+    def _stored(self, ex, k, rank):
+        """Resolve version ``k``'s payload, ``rank``'s copy if it holds one
+        (else whichever rank does)."""
         if ex.n_nodes == 1:
             return ex._stores[0][k]
+        local = ex._stores[rank]
+        if k in local:
+            return local[k]
         return ex._stores[next(iter(ex._where[k]))][k]
 
     @staticmethod
@@ -455,13 +459,21 @@ class FusedBatchBackend(Backend):
         The single override point for subclasses that lower chains to a
         different executable form (the mesh backend swaps in
         ``lookup_chain_pallas`` for kernel-tagged bodies).  Raising any of
-        the scan-tracing error types makes :meth:`_run_chain` pin the fn to
-        per-level dispatch; everything before (eligibility, staging) and
-        after (ships, virtual commit/GC replay) is shared.
+        the scan-tracing error types pins the fn to per-level dispatch and
+        returns None; everything before (eligibility, staging) and after
+        (ships, virtual commit/GC replay) is shared.
         """
         call = ex._exec_cache.lookup_chain(
             chain.fn, layout, width, n_levels, carry_pos, sig_args)
-        return call(*call_args)
+        try:
+            return call(*call_args)
+        except (jax.errors.JAXTypeError, TypeError, ValueError):
+            # not scan-traceable: data-dependent control flow, or the carry
+            # aval is not loop-invariant (fn changes shape/dtype).  Pin the
+            # fn to per-level dispatch — op bodies are pure, re-execution
+            # (per level) is safe.
+            self._no_chain.add(chain.fn)
+            return None
 
     def _run_chain(self, ex, ops, plan, chain) -> bool:
         """Dispatch a :class:`~repro.core.plan.ChainSlice` as one scan call.
@@ -515,8 +527,10 @@ class FusedBatchBackend(Backend):
                    for l in range(1, n_levels) for j in range(width)):
                 exterior[e] = ("inv", [staged[j][e] for j in range(width)])
             else:
-                exterior[e] = ("xs", [[self._stored(ex, k) for k in row]
-                                      for row in keys])
+                exterior[e] = ("xs", [
+                    [self._stored(ex, schedule[m].arg_keys[e],
+                                  schedule[m].exec_ranks[0]) for m in lvl]
+                    for lvl in chain.members])
         # constants: members of one level must agree (they are broadcast,
         # not batched); across levels a position is scan-invariant or — if
         # the values are uniform-typed scalars — hoisted into stacked xs.
@@ -620,16 +634,10 @@ class FusedBatchBackend(Backend):
                 layout.append(XS)
                 call_args.append(stacked)
                 sig_args.append(stacked)
-        try:
-            out = self._dispatch_chain(
-                ex, chain, tuple(layout), width, n_levels, carry_pos,
-                call_args, sig_args)
-        except (jax.errors.JAXTypeError, TypeError, ValueError):
-            # not scan-traceable: data-dependent control flow, or the carry
-            # aval is not loop-invariant (fn changes shape/dtype).  Pin the
-            # fn to per-level dispatch — op bodies are pure, re-execution
-            # (per level) is safe.
-            self._no_chain.add(chain.fn)
+        out = self._dispatch_chain(
+            ex, chain, tuple(layout), width, n_levels, carry_pos,
+            call_args, sig_args)
+        if out is None:
             return False
         self.chains_dispatched += 1
         self.ops_chained += width * n_levels

@@ -23,21 +23,36 @@ in tests/CI; on TPU the identical build runs un-interpreted):
   levels as a ``fori_loop`` — instead of a python-level ``lax.scan`` of
   XLA calls.  Untagged bodies keep the generic scan path.
 
+* **Placement** — while ship lowering is active, rank ``r``'s initial
+  payloads are put on ``devices[r]`` and a shipped row lands on the
+  destination's device, so rank ``r``'s op bodies run on chip ``r``.
+  Fused buckets never mix ranks, and a chain dispatches only when it runs
+  on one rank with no first-level ship (its operands then already sit on
+  that rank's device).
+
 The frontend contract is unchanged: commit/GC/transfer accounting is
 replayed virtually in plan order (the procs-backend pattern), so values,
 stats and the transfer-event stream stay **byte-identical to serial** and
 the backend passes the cross-backend conformance fuzzer unchanged.
 ``ppermute`` moves bits without arithmetic and the pallas chain kernels
-are bitwise-stable in interpret mode, so parity is exact, not approximate.
+are bitwise-stable in interpret mode, so parity on the CPU is exact, not
+approximate.
 
-Graceful degradation (never an error):
+The mode follows the platform: on a TPU the chain kernels compile for the
+chip (chain lowering is armed even with one device); elsewhere they run in
+Pallas interpret mode and ``pallas="auto"`` arms them only on a device
+mesh.  What is not lowered, and never an error:
 
 * fewer than 2 devices, or more plan ranks than devices → ships replay
   simulated (inherited :class:`~.fused.FusedBatchBackend` behaviour);
-* a non-jax / empty payload, or a collective build failure → that ship
-  replays simulated;
-* an untagged chain body, width > 1, a non-width-1 layout, or a pallas
-  trace failure → that chain takes the generic ``jit(lax.scan)`` path.
+* a non-jax / empty payload → that ship replays simulated;
+* an untagged chain body, width > 1, or a non-width-1 layout → that
+  chain takes the generic ``jit(lax.scan)`` path.
+
+What is lowered must succeed: a collective or a tagged chain's Pallas
+kernel that fails to build or run raises — the flush fails under the
+executor's failure contract — instead of quietly replaying on another
+path.
 """
 
 from __future__ import annotations
@@ -47,9 +62,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from ..lowering import broadcast_by_schedule, schedule_for_topology
 from ..stats import TransferEvent, _nbytes
+from .base import BatchSlice
 from .fused import CONST, SINGLE, XS, XS_CONST, FusedBatchBackend
 
 # layouts a width-1 pallas chain executable understands (FLAT/STACKED are
@@ -64,30 +79,32 @@ class MeshBackend(FusedBatchBackend):
     | ``"hierarchical"``); default derives it from the executor's topology
     model via :func:`~repro.core.lowering.schedule_for_topology`.
 
-    ``pallas`` gates chain lowering: ``"auto"`` (default) enables it
-    exactly when ship lowering is active (≥ 2 devices — single-device
-    hosts fall back to ``fused`` wholesale), ``True`` forces it on any
-    host (interpret mode runs on one CPU device; the test suite uses this
-    to counter-assert dispatch without a multi-device subprocess), and
-    ``False`` disables it.
+    ``pallas`` gates chain lowering: ``"auto"`` (default) enables it on a
+    TPU, and elsewhere exactly when ship lowering can be active (≥ 2
+    devices — single-device CPU hosts fall back to ``fused`` wholesale);
+    ``True`` forces it on any host (interpret mode runs on one CPU device;
+    the test suite uses this to counter-assert dispatch without a
+    multi-device subprocess), and ``False`` disables it.  Whether the
+    kernels are interpreted is not an option: it follows the platform
+    (:attr:`interpret`).
     """
 
     name = "mesh"
 
     def __init__(self, min_batch: int = 2, min_chain_levels: int = 2, *,
-                 schedule: str | None = None, pallas="auto",
-                 interpret: bool = True):
+                 schedule: str | None = None, pallas="auto"):
         super().__init__(min_batch, min_chain_levels)
         self.schedule = schedule
         self.pallas = pallas
-        self.interpret = interpret
         self._devices = tuple(jax.devices())
+        # Pallas kernels compile for real only on a TPU; on the CPU they
+        # run through the interpreter
+        self.interpret = self._devices[0].platform != "tpu"
         self._active = False            # ship lowering armed for this plan?
         self._schedule_eff = "tree"     # resolved per execute()
         self._arity = 4
         self._meshes: dict[int, Mesh] = {}
         self._bcast_cache: dict[tuple, object] = {}
-        self._no_pallas: set = set()    # fns whose pallas lowering failed
         # observability: counter-asserted by tests/benchmarks
         self.ships_lowered = 0          # ship schedules run as collectives
         self.ships_simulated = 0        # ship schedules replayed simulated
@@ -97,18 +114,42 @@ class MeshBackend(FusedBatchBackend):
     # -- per-plan arming ------------------------------------------------------
     def _pallas_enabled(self) -> bool:
         if self.pallas == "auto":
-            return len(self._devices) >= 2
+            return not self.interpret or len(self._devices) >= 2
         return bool(self.pallas)
 
+    def _lowers_ships(self, n_nodes: int) -> bool:
+        """Ranks map onto devices (ships lower, payloads are placed)."""
+        return len(self._devices) >= 2 and 2 <= n_nodes <= len(self._devices)
+
     def execute(self, ex, wf, plan) -> None:
-        self._active = (len(self._devices) >= 2
-                        and 2 <= ex.n_nodes <= len(self._devices))
+        self._active = self._lowers_ships(ex.n_nodes)
         if self._active:
             topo = getattr(ex, "topology", None)
             self._schedule_eff = (self.schedule
                                   or schedule_for_topology(topo))
             self._arity = max(2, int(getattr(topo, "arity", 4) or 4))
         super().execute(ex, wf, plan)
+
+    def place(self, ex, rank: int, payload):
+        if self._lowers_ships(ex.n_nodes) and isinstance(payload, jax.Array):
+            return jax.device_put(payload, self._devices[rank])
+        return payload
+
+    def misplaced(self, ex) -> list:
+        """``(rank, version key)`` of every stored device payload that does
+        not live on its rank's device while ranks map onto devices (empty
+        otherwise) — the placement invariant, for tests and smoke runs."""
+        if not self._lowers_ships(ex.n_nodes):
+            return []
+        bad = []
+        for rank, store in ex._stores.items():
+            want = {self._devices[rank]}
+            for vkey, payload in store.items():
+                arr = (payload.buffer if type(payload) is BatchSlice
+                       else payload)
+                if isinstance(arr, jax.Array) and arr.devices() != want:
+                    bad.append((rank, vkey))
+        return bad
 
     def _delegate_wholesale(self, ex, wf, plan) -> bool:
         # while lowering is armed, multi-rank plans stay on the level loop
@@ -140,15 +181,15 @@ class MeshBackend(FusedBatchBackend):
                 return broadcast_by_schedule(x, sched, "r", root=root,
                                              arity=arity)
 
-            smapped = shard_map(body, mesh=mesh, in_specs=spec,
-                                out_specs=spec, check_vma=False)
+            smapped = jax.shard_map(body, mesh=mesh, in_specs=spec,
+                                    out_specs=spec, check_vma=False)
             call = (jax.jit(smapped), mesh, spec)
             self._bcast_cache[key] = call
         return call
 
     def _broadcast_rows(self, payload, root: int, n: int):
-        """Run one rooted broadcast on the device mesh; returns the global
-        ``(n, *shape)`` result whose every row holds the payload's bits."""
+        """Run one rooted broadcast on the device mesh; returns the ``n``
+        received ``(1, *shape)`` shards, shard ``r`` on ``devices[r]``."""
         call, mesh, spec = self._bcast_call(
             n, root, payload.shape, payload.dtype)
         # root row carries the payload, every other row is zeros — the
@@ -157,7 +198,9 @@ class MeshBackend(FusedBatchBackend):
         buf = jnp.zeros((n,) + payload.shape, payload.dtype)
         buf = buf.at[root].set(payload)
         buf = jax.device_put(buf, NamedSharding(mesh, spec))
-        return call(buf)
+        out = call(buf)
+        shards = {s.device: s.data for s in out.addressable_shards}
+        return [shards[d] for d in self._devices[:n]]
 
     def _apply_ships(self, ex, p) -> None:
         if not self._active:
@@ -173,10 +216,7 @@ class MeshBackend(FusedBatchBackend):
             payload = stores[root][vkey]
             rows = None
             if isinstance(payload, jax.Array) and payload.size:
-                try:
-                    rows = self._broadcast_rows(payload, root, n)
-                except Exception:   # collective build/run failure: simulate
-                    rows = None
+                rows = self._broadcast_rows(payload, root, n)
             if rows is None:
                 self.ships_simulated += 1
             else:
@@ -187,32 +227,51 @@ class MeshBackend(FusedBatchBackend):
             nb = _nbytes(payload)
             ranks = where[vkey]
             for src, dst, kind, rel in transfers:
-                stores[dst][vkey] = payload if rows is None else rows[dst]
+                stores[dst][vkey] = (payload if rows is None
+                                     else rows[dst][0])
                 ranks.add(dst)
                 ex._live_entries += 1
                 events.append(
                     TransferEvent(vkey, src, dst, nb, base_round + rel,
                                   kind, wavefront))
 
+    # -- rank-local fusion while payloads are placed ----------------------------
+    def _run_bucket(self, ex, staged, members, results, result_nbytes) -> None:
+        if not self._active:
+            super()._run_bucket(ex, staged, members, results, result_nbytes)
+            return
+        # a stacked dispatch runs on one device: bucket per executing rank
+        by_rank: dict[int, list] = {}
+        for m in members:
+            by_rank.setdefault(staged[m][0].exec_ranks[0], []).append(m)
+        for group in by_rank.values():
+            if len(group) >= self.min_batch:
+                super()._run_bucket(ex, staged, group, results,
+                                    result_nbytes)
+
+    def _run_chain(self, ex, ops, plan, chain) -> bool:
+        if self._active:
+            schedule = plan.schedule
+            ranks = {schedule[i].exec_ranks for lvl in chain.members
+                     for i in lvl}
+            if (len(ranks) != 1 or len(next(iter(ranks))) != 1
+                    or any(schedule[i].ships for i in chain.members[0])):
+                return False    # operands span devices: per-level path
+        return super()._run_chain(ex, ops, plan, chain)
+
     # -- chain lowering -------------------------------------------------------
     def _dispatch_chain(self, ex, chain, layout, width, n_levels, carry_pos,
                         call_args, sig_args):
         if (width == 1 and chain.lowerable is not None
-                and chain.fn not in self._no_pallas
                 and self._pallas_enabled()
                 and set(layout) <= _PALLAS_LAYOUTS):
-            try:
-                call = ex._exec_cache.lookup_chain_pallas(
-                    chain.fn, layout, n_levels, carry_pos, sig_args,
-                    interpret=self.interpret)
-                out = call(*call_args)
-            except Exception:
-                # pallas trace/lowering failed for this body: pin the fn to
-                # the generic scan path (NOT _no_chain — the scan is fine)
-                self._no_pallas.add(chain.fn)
-            else:
-                self.pallas_chains_dispatched += 1
-                self.ops_pallas += n_levels
-                return out
+            # a tagged body asserts it lowers: a failure here raises
+            call = ex._exec_cache.lookup_chain_pallas(
+                chain.fn, layout, n_levels, carry_pos, sig_args,
+                interpret=self.interpret)
+            out = call(*call_args)
+            self.pallas_chains_dispatched += 1
+            self.ops_pallas += n_levels
+            return out
         return super()._dispatch_chain(ex, chain, layout, width, n_levels,
                                        carry_pos, call_args, sig_args)

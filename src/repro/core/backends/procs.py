@@ -43,6 +43,7 @@ import os
 import pickle
 import signal
 import tempfile
+import threading
 import time
 import weakref
 
@@ -61,6 +62,10 @@ _UID_SEQ = itertools.count(1)
 # Inside a pool worker this is the worker's rank; None in the frontend.
 # Observability for op bodies and tests (e.g. hang exactly one rank).
 _CURRENT_RANK = None
+# Workers start with JAX_PLATFORMS=cpu: a chip belongs to one process, and
+# the parent holds it.  A spawned child inherits os.environ at start, so
+# the parent swaps the variable in around Process.start() under this lock.
+_SPAWN_ENV_LOCK = threading.Lock()
 
 
 # ---------------------------------------------------------------------------
@@ -288,7 +293,16 @@ class WorkerPool:
                   self.hb_path(rank), self.hb_interval,
                   self.barrier_timeout),
             daemon=True, name=f"bind-rank{rank}")
-        p.start()
+        with _SPAWN_ENV_LOCK:
+            prev = os.environ.get("JAX_PLATFORMS")
+            os.environ["JAX_PLATFORMS"] = "cpu"
+            try:
+                p.start()
+            finally:
+                if prev is None:
+                    del os.environ["JAX_PLATFORMS"]
+                else:
+                    os.environ["JAX_PLATFORMS"] = prev
         child.close()
         self.procs[rank] = p
         self.conns[rank] = parent

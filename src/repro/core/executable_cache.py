@@ -21,6 +21,7 @@ permanently.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable
 
 import jax
@@ -241,21 +242,21 @@ class ExecutableCache:
 
     def lookup_chain_pallas(self, fn: Callable, layout: tuple, n_levels: int,
                             carry_pos: int, sig_args, *,
-                            interpret: bool = True) -> Callable:
+                            interpret: bool) -> Callable:
         """Resolve a *Pallas* chain executable: the whole ``n_levels`` run of
-        a width-1 kernel-bodied chain compiled into ONE ``pl.pallas_call``.
+        a width-1 kernel-bodied chain compiled into ONE ``pl.pallas_call``
+        (built by :func:`chain_pallas_call`, which documents the tiling).
 
         Where :meth:`lookup_chain` scans a python-level ``fn`` with
         ``lax.scan`` (one XLA loop around per-level ops), this lowers the
-        chain *into* a Pallas kernel: every tensor operand becomes a kernel
-        ref, the levels run as a ``fori_loop`` over the refs (per-level
-        ``"xs"``/``"xs_const"`` operands are dynamic leading-dim loads), and
-        only the final carry is written out.  ``interpret=True`` executes
-        the kernel on CPU; on TPU the same build compiles for real.  Only
-        op bodies annotated ``__bind_kernel__`` (the executor-callable
-        entry points of ``repro.kernels.*.ops``) should be resolved here —
-        the tag asserts the body is a pure shape-preserving array function
-        a Pallas block can evaluate.
+        chain *into* a Pallas kernel and writes only the final carry.
+        ``interpret=True`` executes the kernel on CPU; on TPU the same
+        build compiles for real.  Only op bodies annotated
+        ``__bind_kernel__`` (the executor-callable entry points of
+        ``repro.kernels.*.ops``) may be resolved here — the tag asserts the
+        body is a pure shape-preserving array function a Pallas block can
+        evaluate, so a trace or lowering failure is an error: it raises
+        (the entry is evicted, see :meth:`_resolve`).
 
         Layout vocabulary is the width-1 subset of :meth:`lookup_chain`:
         ``"single"`` (carry or chain-invariant exterior), ``"xs"`` /
@@ -264,62 +265,12 @@ class ExecutableCache:
         kernel; the cache key carries their values) so the kernel body sees
         exactly the python scalars serial replay passes — Pallas operands
         would round-trip them through arrays and could flip a weak dtype.
-
-        Tracing/lowering failures follow the :meth:`_resolve` contract: the
-        entry is evicted and the caller falls back to the generic scan.
         """
         key = ((fn, "chain_pallas", layout, n_levels, carry_pos, interpret)
                + tuple(("const", a) if lay == "const" else _abstract(a)
                        for lay, a in zip(layout, sig_args)))
-        tensor_pos = tuple(i for i, lay in enumerate(layout)
-                           if lay != "const")
-        const_pos = tuple(i for i, lay in enumerate(layout)
-                          if lay == "const")
-
-        def build():
-            from repro.compat import import_pallas
-            pl = import_pallas()
-            if pl is None:
-                raise RuntimeError(
-                    "jax.experimental.pallas unavailable in this install")
-
-            def chain_call(*flat):
-                consts = {p: flat[p] for p in const_pos}
-
-                def kernel(*refs):
-                    out_ref = refs[-1]
-                    ref_of = dict(zip(tensor_pos, refs))
-
-                    def body(i, carry):
-                        call_args = []
-                        for p, lay in enumerate(layout):
-                            if p == carry_pos:
-                                call_args.append(carry)
-                            elif lay == "const":
-                                call_args.append(consts[p])
-                            elif lay in ("xs", "xs_const"):
-                                call_args.append(ref_of[p][i])
-                            else:               # "single": chain-invariant
-                                call_args.append(ref_of[p][...])
-                        out = fn(*call_args)
-                        if isinstance(out, tuple):
-                            out = out[0]        # chain ops write one payload
-                        return out
-
-                    out_ref[...] = jax.lax.fori_loop(
-                        0, n_levels, body, ref_of[carry_pos][...])
-
-                carry0 = flat[carry_pos]
-                return pl.pallas_call(
-                    kernel,
-                    out_shape=jax.ShapeDtypeStruct(carry0.shape,
-                                                   carry0.dtype),
-                    interpret=interpret,
-                )(*(flat[p] for p in tensor_pos))
-
-            return jax.jit(chain_call, static_argnums=const_pos)
-
-        return self._resolve(key, build)
+        return self._resolve(key, lambda: chain_pallas_call(
+            fn, layout, n_levels, carry_pos, interpret=interpret))
 
     # -- entry construction ---------------------------------------------------
     def _build(self, key: tuple, fn: Callable, args) -> Callable:
@@ -353,6 +304,161 @@ class ExecutableCache:
             return out
 
         return first_call
+
+
+# VMEM the chain kernel's pipelined blocks may take (double buffers included)
+# when it picks a row-block height, and the scoped VMEM limit it asks the
+# compiler for; the gap is room for the op body's temporaries.  A TPU v5e
+# TensorCore has 128 MiB of VMEM, the compiler's default scope is 16 MiB.
+CHAIN_BLOCK_BUDGET = 24 << 20
+CHAIN_VMEM_LIMIT = 64 << 20
+_MAX_BLOCK_ROWS = 512
+
+
+def _row_positions(fn: Callable, layout: tuple) -> frozenset:
+    """Argument positions a chain kernel may cut into row blocks.
+
+    The ``__bind_kernel__`` tag says how the body treats its rows:
+    ``"ewise"`` bodies are elementwise in every operand; ``"dot"`` bodies
+    (``c + a @ b``, ``o + softmax(q kᵀ) v``) are row-separable in their
+    first two arguments — the carry and its row-aligned left operand —
+    while the later ones are contraction partners every row block reads
+    whole.  Hoisted per-level scalars live in SMEM and are never blocked.
+    """
+    kind = getattr(fn, "__bind_kernel__", None)
+    tensors = [p for p, lay in enumerate(layout)
+               if lay not in ("const", "xs_const")]
+    if kind == "ewise":
+        return frozenset(tensors)
+    if kind == "dot":
+        return frozenset(p for p in (0, 1) if p in tensors)
+    return frozenset()
+
+
+def _block_rows(rows: int, itemsize: int, row_bytes: int,
+                whole_bytes: int) -> int:
+    """Row-block height: the largest legal divisor of ``rows`` (a multiple
+    of the dtype's sublane tile, at most ``_MAX_BLOCK_ROWS``) whose double
+    buffers fit :data:`CHAIN_BLOCK_BUDGET`; the smallest legal one when
+    none fits (the compiler then says what is over), ``rows`` when no
+    divisor is legal."""
+    step = 8 * max(1, 4 // itemsize)
+    cands = [b for b in range(step, min(rows, _MAX_BLOCK_ROWS) + 1, step)
+             if rows % b == 0]
+    if not cands:
+        return rows
+    fitting = [b for b in cands
+               if 2 * (b * row_bytes + whole_bytes) <= CHAIN_BLOCK_BUDGET]
+    return max(fitting) if fitting else cands[0]
+
+
+def chain_pallas_call(fn: Callable, layout: tuple, n_levels: int,
+                      carry_pos: int, *, interpret: bool) -> Callable:
+    """Build the jitted ``pallas_call`` running ``n_levels`` levels of a
+    width-1 kernel-tagged chain (see
+    :meth:`ExecutableCache.lookup_chain_pallas`; constants are static).
+
+    The kernel's grid is ``(row blocks, levels)``.  The output block is the
+    carry: loaded from the carry operand at level 0, resident in VMEM
+    across the (innermost, sequential) level axis, and written back once
+    per row block.  Each level's ``"xs"`` slice arrives as its own block,
+    so only one level of a stacked operand is in VMEM at a time; hoisted
+    ``"xs_const"`` scalars sit whole in SMEM.  Operands the tag lets the
+    kernel row-block (:func:`_row_positions`) and whose per-level shape
+    matches the carry's rank and row count are cut into ``(bm, ...)``
+    blocks; the others are loaded whole.  Carries of rank < 2 are one
+    block.
+    """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    const_pos = tuple(p for p, lay in enumerate(layout) if lay == "const")
+    tensor_pos = tuple(p for p, lay in enumerate(layout) if lay != "const")
+    row_pos = _row_positions(fn, layout)
+
+    def chain_call(*flat):
+        carry0 = flat[carry_pos]
+        shape, dtype = tuple(carry0.shape), carry0.dtype
+
+        def level_shape(p):
+            s = tuple(flat[p].shape)
+            return s[1:] if layout[p] in ("xs", "xs_const") else s
+
+        blocked = frozenset()
+        bm = n_blocks = 1
+        if len(shape) >= 2 and carry_pos in row_pos:
+            blocked = frozenset(
+                p for p in row_pos
+                if len(level_shape(p)) == len(shape)
+                and level_shape(p)[0] == shape[0])
+        if blocked:
+            row_bytes = sum(math.prod(level_shape(p)[1:])
+                            * flat[p].dtype.itemsize for p in blocked)
+            row_bytes += math.prod(shape[1:]) * dtype.itemsize   # output
+            whole_bytes = sum(math.prod(level_shape(p))
+                              * flat[p].dtype.itemsize
+                              for p in tensor_pos
+                              if p not in blocked
+                              and layout[p] != "xs_const")
+            bm = _block_rows(shape[0], dtype.itemsize, row_bytes,
+                             whole_bytes)
+            n_blocks = shape[0] // bm
+
+        def spec(p):
+            lay = layout[p]
+            if lay == "xs_const":
+                return pl.BlockSpec(memory_space=pltpu.SMEM)
+            s = level_shape(p)
+            if p in blocked:
+                block = (bm,) + s[1:]
+                rest = (0,) * (len(s) - 1)
+                if lay == "xs":
+                    return pl.BlockSpec((None,) + block,
+                                        lambda r, l: (l, r) + rest)
+                return pl.BlockSpec(block, lambda r, l: (r,) + rest)
+            zeros = (0,) * len(s)
+            if lay == "xs":
+                return pl.BlockSpec((None,) + s, lambda r, l: (l,) + zeros)
+            return pl.BlockSpec(s, lambda r, l: zeros)
+
+        def kernel(*refs):
+            out_ref = refs[-1]
+            ref_of = dict(zip(tensor_pos, refs))
+            level = pl.program_id(1)
+
+            @pl.when(level == 0)
+            def _load_carry():
+                out_ref[...] = ref_of[carry_pos][...]
+
+            call_args = []
+            for p, lay in enumerate(layout):
+                if p == carry_pos:
+                    call_args.append(out_ref[...])
+                elif lay == "const":
+                    call_args.append(flat[p])
+                elif lay == "xs_const":
+                    call_args.append(ref_of[p][level])
+                else:                   # "single" or this level's "xs" block
+                    call_args.append(ref_of[p][...])
+            out = fn(*call_args)
+            if isinstance(out, tuple):
+                out = out[0]            # chain ops write one payload
+            out_ref[...] = out
+
+        return pl.pallas_call(
+            kernel,
+            grid=(n_blocks, n_levels),
+            in_specs=[spec(p) for p in tensor_pos],
+            out_specs=spec(carry_pos),
+            out_shape=jax.ShapeDtypeStruct(shape, dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=CHAIN_VMEM_LIMIT),
+            interpret=interpret,
+            name=f"bind_chain_{getattr(fn, '__name__', 'fn')}",
+        )(*(flat[p] for p in tensor_pos))
+
+    return jax.jit(chain_call, static_argnums=const_pos)
 
 
 # Process-wide cache: signatures are shared across executors and workflows

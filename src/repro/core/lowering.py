@@ -30,8 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size
-
 
 # ---------------------------------------------------------------------------
 # Paper-faithful binary-tree collectives (log-depth ppermute schedules)
@@ -45,7 +43,7 @@ def tree_reduce(x: jax.Array, axis_name: str) -> jax.Array:
     other ranks hold garbage partials (callers follow with a broadcast or
     discard).  Mirrors Listing 1's ``for (s = 1; s < nt; s *= 2)`` loop.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     s = 1
     while s < n:
@@ -59,7 +57,7 @@ def tree_reduce(x: jax.Array, axis_name: str) -> jax.Array:
 
 def tree_broadcast(x: jax.Array, axis_name: str) -> jax.Array:
     """Binary-tree broadcast from rank 0 of ``axis_name`` (log₂ n rounds)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     if n == 1:
         return x
@@ -149,7 +147,7 @@ def allreduce_by_schedule(
         outer, inner = data_axes[0], data_axes[-1]
         scat = scatter_dimension
         if scat is None:
-            inner_n = axis_size(inner)
+            inner_n = lax.axis_size(inner)
             scat = next(
                 (d for d in range(x.ndim) if x.shape[d] % inner_n == 0), None
             )
@@ -177,7 +175,7 @@ def allreduce_by_schedule(
 
 def tree_broadcast_from(x: jax.Array, axis_name: str, root: int = 0) -> jax.Array:
     """Binary-tree broadcast from ``root`` (log₂ n ppermute rounds)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = lax.axis_index(axis_name)
@@ -200,7 +198,7 @@ def ring_broadcast(x: jax.Array, axis_name: str, root: int = 0) -> jax.Array:
     right schedule when the topology model says distant hops are expensive
     (a 1-D torus), and the baseline the tree must beat elsewhere.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = lax.axis_index(axis_name)
@@ -221,7 +219,7 @@ def hierarchical_broadcast(x: jax.Array, axis_name: str, root: int = 0,
     phase 2 tree-broadcasts inside every group concurrently (the cheap
     intra-switch hops).  Cross-switch rounds drop to ⌈log₂⌈n/arity⌉⌉.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = lax.axis_index(axis_name)
@@ -300,7 +298,7 @@ def sync_gradients(
     """All-reduce every leaf of a gradient pytree with the chosen schedule."""
     n = 1
     for ax in data_axes:
-        n *= axis_size(ax)
+        n *= lax.axis_size(ax)
 
     def _one(g):
         out = allreduce_by_schedule(g, schedule, data_axes=data_axes)

@@ -355,7 +355,7 @@ def plan_recovery(ex, wf, needed: Iterable, *, rank_map: dict = None,
                 rank = wf.initial[k][1]
                 if rank_map:
                     rank = rank_map.get(rank, rank)
-            ex._place(rank, k, payload)
+            ex._place(rank, k, ex.backend.place(ex, rank, payload))
             restored += 1
             continue
         prod = producers.get(k)
@@ -363,7 +363,7 @@ def plan_recovery(ex, wf, needed: Iterable, *, rank_map: dict = None,
             payload, rank = wf.initial[k]
             if rank_map:
                 rank = rank_map.get(rank, rank)
-            ex._place(rank, k, payload)
+            ex._place(rank, k, ex.backend.place(ex, rank, payload))
             replaced += 1
             continue
         if prod.op_id in op_ids:

@@ -530,7 +530,8 @@ class LocalExecutor:
                 if vkey not in self._where:
                     if rm:
                         rank = rm.get(rank, rank)
-                    self._place(rank, vkey, payload)
+                    self._place(rank, vkey,
+                                self.backend.place(self, rank, payload))
             self._init_seen = upto
 
     def _flush(self) -> ExecutionStats:

@@ -3,7 +3,7 @@
 The recurrence behind RG-LRU (RecurrentGemma) and the sLSTM cell/normaliser
 states.  GPU implementations lean on warp-level shuffles; the TPU-native
 adaptation is *chunked*: the sequence is cut into VMEM-resident blocks, a
-log-depth associative scan runs **inside** the block on the VPU, and a tiny
+log-depth (Hillis–Steele) scan runs **inside** the block on the VPU, and a tiny
 (1, d) carry persists in VMEM scratch across the sequential grid sweep —
 sequential dependencies cross blocks only through that carry, so HBM traffic
 is exactly one read of (a, x) and one write of y.
@@ -22,11 +22,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _scan_combine(c1, c2):
-    a1, x1 = c1
-    a2, x2 = c2
-    # (a2, x2) ∘ (a1, x1): y = a2*(a1*y_prev + x1) + x2
-    return a1 * a2, a2 * x1 + x2
+def _block_scan(a, x):
+    """Inclusive prefix scan of the (a, x) affine maps down axis 0.
+
+    Hillis–Steele, log2(rows) steps: each step composes every row with the
+    row ``k`` above it, brought in by a sublane rotation (``pltpu.roll``);
+    rows ``< k`` have nothing above and keep their value.  Only full-block
+    rolls and selects, so Mosaic lowers it (an in-kernel
+    ``lax.associative_scan`` slices down to zero-size vectors, which it
+    refuses).
+    """
+    row = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    k = 1
+    while k < a.shape[0]:
+        a_up = pltpu.roll(a, k, 0)
+        x_up = pltpu.roll(x, k, 0)
+        has_up = row >= k
+        # (a, x) ∘ (a_up, x_up): y = a*(a_up*y_prev + x_up) + x
+        x = jnp.where(has_up, a * x_up + x, x)
+        a = jnp.where(has_up, a * a_up, a)
+        k *= 2
+    return a, x
 
 
 def _linear_scan_kernel(a_ref, x_ref, y_ref, h_ref, *, n_chunks: int):
@@ -40,7 +56,7 @@ def _linear_scan_kernel(a_ref, x_ref, y_ref, h_ref, *, n_chunks: int):
     x = x_ref[0].astype(jnp.float32)      # (bs, d)
     # In-block prefix scan (log2(bs) VPU steps):
     #   y_t = A_t * h_in + X_t with (A, X) = scan of (a, x)
-    A, X = jax.lax.associative_scan(_scan_combine, (a, x), axis=0)
+    A, X = _block_scan(a, x)
     h_in = h_ref[...]                     # (1, d)
     y = A * h_in + X
     y_ref[0] = y.astype(y_ref.dtype)

@@ -18,13 +18,12 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
-from repro.compat import shard_map  # noqa: E402
 from repro.core import lowering  # noqa: E402
 
 
 def _run_1d(fn, x, n=8):
     mesh = jax.make_mesh((n,), ("i",))
-    f = shard_map(
+    f = jax.shard_map(
         fn, mesh=mesh, in_specs=P("i"), out_specs=P("i"), check_vma=False
     )
     return np.asarray(jax.jit(f)(x))
@@ -68,7 +67,7 @@ def main() -> None:
     def hier(v):
         return lowering.hierarchical_allreduce(v, "data", "pod", scatter_dimension=1)
 
-    f = shard_map(
+    f = jax.shard_map(
         hier, mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(("pod", "data")),
         check_vma=False,
     )
@@ -83,7 +82,7 @@ def main() -> None:
                 v, s, data_axes=("pod", "data")
             )
 
-        f = shard_map(
+        f = jax.shard_map(
             sync, mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(("pod", "data")),
             check_vma=False,
         )
@@ -102,7 +101,7 @@ def main() -> None:
     def sync_tree(g):
         return lowering.sync_gradients(g, "hierarchical", ("pod", "data"))
 
-    f = shard_map(
+    f = jax.shard_map(
         sync_tree, mesh=mesh,
         in_specs=({"w": P(("pod", "data")), "b": P(("pod", "data"))},),
         out_specs={"w": P(("pod", "data")), "b": P(("pod", "data"))},

@@ -63,7 +63,10 @@ def main(argv=None) -> None:
     from repro.train.step import make_train_step, make_manual_dp_train_step
     from repro.runtime.supervisor import touch_heartbeat
     from repro.launch.mesh import make_host_mesh
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.sharding import make_policy
+
+    enable_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.reduced:

@@ -16,13 +16,9 @@ Two implementations of the same algorithm:
 
 from __future__ import annotations
 
-import numpy as np
-
 import jax
 from jax import lax
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro import core as bind
 from repro.core import lowering
@@ -67,30 +63,33 @@ def distributed_gemm_listing1(
                 wf.call(_t_iadd, (c.tile(i, k), r[0]), name="iadd")
 
 
-def make_distributed_inputs(
-    wf: bind.Workflow, A: np.ndarray, B: np.ndarray, ib: int, NP: int, NQ: int
-):
-    """Tile + distribute operands the way the algorithm's placement expects."""
+def make_distributed_inputs(wf: bind.Workflow, A, B, ib: int, NP: int,
+                            NQ: int):
+    """Tile + distribute operands the way the algorithm's placement expects
+    (NumPy inputs give host tiles, JAX inputs device tiles)."""
     a = Tiled.from_array(wf, A, ib, "A", rank_of=lambda i, j: owner_rank(i, j, NP, NQ))
     b = Tiled.from_array(wf, B, ib, "B", rank_of=lambda j, k: owner_rank(k, j, NP, NQ))
     mt, nt = A.shape[0] // ib, B.shape[1] // ib
     c = Tiled.zeros(wf, mt, nt, ib, A.dtype, "C",
-                    rank_of=lambda i, k: owner_rank(i, k, NP, NQ))
+                    rank_of=lambda i, k: owner_rank(i, k, NP, NQ), xp=a.xp)
     return a, b, c
 
 
 def run_distributed_gemm(
-    A: np.ndarray, B: np.ndarray, *, ib: int, NP: int, NQ: int,
-    collective_mode: str = "tree", backend: str = "serial",
+    A, B, *, ib: int, NP: int, NQ: int,
+    collective_mode: str = "tree", backend="serial",
     topology=None,
-) -> tuple[np.ndarray, "bind.ExecutionStats", float]:
+) -> tuple:
     """Record + execute Listing 1 end-to-end on a chosen execution backend.
 
     Convenience driver for ablations: returns ``(C, stats, est_makespan)``
     where ``est_makespan`` is the simulated communication makespan under
     ``topology`` (``0.0`` when no topology is given).  ``backend`` is a
-    :mod:`repro.core.backends` name — all backends produce identical values
-    and transfer streams, so this is the knob for timing comparisons only.
+    :mod:`repro.core.backends` name or instance — all backends produce
+    identical values and transfer streams, so this is the knob for timing
+    comparisons only.  ``A``/``B`` are NumPy (host tiles, ``C`` NumPy) or
+    JAX arrays (device tiles, ``C`` a JAX array — see
+    :meth:`Tiled.to_array`).
     """
     ex = bind.LocalExecutor(NP * NQ, collective_mode=collective_mode,
                             backend=backend)
@@ -127,7 +126,7 @@ def distributed_gemm_shardmap(
             raise ValueError(schedule)
         return part
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(p_axis, q_axis), P(q_axis, None)),

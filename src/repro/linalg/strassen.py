@@ -54,7 +54,8 @@ def gemm_strassen(a: TileView, b: TileView, c: TileView, leaf_nt: int = 1) -> No
     T7 = B21.add(B22, "t7")
 
     wf = c.wf
-    M = [Tiled.zeros(wf, h, h, c.base.ib, c.base.dtype, name=f"m{i+1}")
+    M = [Tiled.zeros(wf, h, h, c.base.ib, c.base.dtype, name=f"m{i+1}",
+                     xp=c.base.xp)
          for i in range(7)]
 
     gemm_strassen(S1, T1, M[0], leaf_nt)

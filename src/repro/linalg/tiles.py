@@ -6,12 +6,18 @@ tile a versioned :class:`~repro.core.trace.BindArray` holding a contiguous
 handles), mirroring the paper's ``a.subset(i, j, mt, nt)``; arithmetic between
 tile grids records per-tile Bind ops, so a whole Strassen recursion becomes
 one transactional DAG.
+
+Tiles keep their input's array type: a NumPy matrix yields NumPy tiles (the
+executor runs their ops as plain Python), a JAX array yields device-resident
+JAX tiles in the input dtype (their ops compile and run on the device).
 """
 
 from __future__ import annotations
 
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro import core as bind
@@ -119,17 +125,23 @@ class TileView:
 
 
 class Tiled(TileView):
-    """An owning tile grid. ``Tiled.from_array`` splits a dense matrix."""
+    """An owning tile grid. ``Tiled.from_array`` splits a dense matrix.
+
+    ``xp`` is the tiles' array namespace: ``numpy`` for host tiles,
+    ``jax.numpy`` for device tiles.
+    """
 
     def __init__(self, wf: bind.Workflow, mt: int, nt: int, ib: int,
-                 dtype=np.float64, materialise: bool = True, name: str = "T"):
+                 dtype=np.float64, materialise: bool = True, name: str = "T",
+                 xp=np):
         self._wf = wf
         self.ib = ib
         self.dtype = dtype
         self.name = name
+        self.xp = xp
         if materialise:
             self._tiles = [
-                [wf.array(np.zeros((ib, ib), dtype), f"{name}[{i},{j}]")
+                [wf.array(xp.zeros((ib, ib), dtype), f"{name}[{i},{j}]")
                  for j in range(nt)]
                 for i in range(mt)
             ]
@@ -139,35 +151,42 @@ class Tiled(TileView):
 
     # -- constructors -----------------------------------------------------------
     @classmethod
-    def from_array(cls, wf: bind.Workflow, a: np.ndarray, ib: int,
+    def from_array(cls, wf: bind.Workflow, a, ib: int,
                    name: str = "T", rank_of=None) -> "Tiled":
+        """Split ``a`` (NumPy, or a JAX array kept on its device) into
+        ``ib × ib`` tiles; ``rank_of(i, j)`` places tile ``(i, j)``."""
         m, n = a.shape
         assert m % ib == 0 and n % ib == 0, (a.shape, ib)
         mt, nt = m // ib, n // ib
-        t = cls(wf, mt, nt, ib, a.dtype, materialise=False, name=name)
+        on_device = isinstance(a, jax.Array)
+        t = cls(wf, mt, nt, ib, a.dtype, materialise=False, name=name,
+                xp=jnp if on_device else np)
         for i in range(mt):
             for j in range(nt):
-                block = np.ascontiguousarray(a[i * ib:(i + 1) * ib, j * ib:(j + 1) * ib])
+                block = a[i * ib:(i + 1) * ib, j * ib:(j + 1) * ib]
+                if not on_device:
+                    block = np.ascontiguousarray(block)
                 rank = rank_of(i, j) if rank_of is not None else 0
                 t._tiles[i][j] = wf.array(block, f"{name}[{i},{j}]", rank=rank)
         return t
 
     @classmethod
     def zeros(cls, wf: bind.Workflow, mt: int, nt: int, ib: int,
-              dtype=np.float64, name: str = "T", rank_of=None) -> "Tiled":
-        t = cls(wf, mt, nt, ib, dtype, materialise=False, name=name)
+              dtype=np.float64, name: str = "T", rank_of=None,
+              xp=np) -> "Tiled":
+        t = cls(wf, mt, nt, ib, dtype, materialise=False, name=name, xp=xp)
         for i in range(mt):
             for j in range(nt):
                 rank = rank_of(i, j) if rank_of is not None else 0
                 t._tiles[i][j] = wf.array(
-                    np.zeros((ib, ib), dtype), f"{name}[{i},{j}]", rank=rank)
+                    xp.zeros((ib, ib), dtype), f"{name}[{i},{j}]", rank=rank)
         return t
 
     @classmethod
     def like(cls, view: TileView, name: str = "tmp") -> "Tiled":
         base = view.base
         return cls(base.wf, view.mt, view.nt, base.ib, base.dtype,
-                   materialise=False, name=name)
+                   materialise=False, name=name, xp=base.xp)
 
     # -- grid access ------------------------------------------------------------
     @property
@@ -183,12 +202,17 @@ class Tiled(TileView):
         self._tiles[i][j] = arr
 
     # -- read back ---------------------------------------------------------------
-    def to_array(self) -> np.ndarray:
-        rows = []
-        for i in range(self.mt):
-            row = [np.asarray(self.wf.fetch(self.tile(i, j))) for j in range(self.nt)]
-            rows.append(np.concatenate(row, axis=1))
-        return np.concatenate(rows, axis=0)
+    def to_array(self):
+        """The dense matrix: NumPy for host tiles; for device tiles a JAX
+        array on the device of tile ``(0, 0)`` (tiles placed on other
+        devices are copied there)."""
+        grid = [[self.wf.fetch(self.tile(i, j)) for j in range(self.nt)]
+                for i in range(self.mt)]
+        if self.xp is np:
+            return np.block([[np.asarray(t) for t in row] for row in grid])
+        dev = next(iter(grid[0][0].devices()))
+        return jnp.block([[jax.device_put(t, dev) for t in row]
+                          for row in grid])
 
 
 def gemm_tiles(a: TileView, b: TileView, c: TileView) -> None:

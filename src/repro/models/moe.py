@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding.constraints import current_policy
@@ -165,7 +164,7 @@ def moe_layer(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
 
         out_specs = (x_spec, P())
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         run, mesh=mesh, in_specs=(p_spec, x_spec), out_specs=out_specs,
         check_vma=False,
     )(p, x)

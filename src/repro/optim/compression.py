@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size
 
 BLOCK = 256
 
@@ -63,7 +62,7 @@ def compressed_allreduce(
     codes, scale = quantize_int8(xf)          # codes: (nb, BLOCK) int8
     q = dequantize_int8(codes, scale, xf.shape)
     new_error = xf - q                         # what compression lost
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     all_codes = lax.all_gather(codes, axis_name)      # (n, nb, BLOCK) s8
     all_scales = lax.all_gather(scale, axis_name)     # (n, nb) f32
     blocks = all_codes.astype(jnp.float32) * all_scales[..., None]

@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.core import lowering
 from repro.sharding.constraints import use_policy
 
@@ -160,7 +158,7 @@ def make_manual_dp_train_step(
         rep = P()
         batch_spec = jax.tree_util.tree_map(
             lambda x: P(data_axes, *([None] * (x.ndim - 1))), batch)
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(rep, rep, batch_spec, rep),
             out_specs=(rep, rep, rep, rep),

@@ -89,7 +89,7 @@ def test_lookup_chain_pallas_matches_python_loop_bitwise():
                     for i in range(n_levels)])
     layout = ("single", "const", "xs")
     call = cache.lookup_chain_pallas(scan_step, layout, n_levels, 0,
-                                     [y0, 0.5, xs])
+                                     [y0, 0.5, xs], interpret=True)
     out = np.asarray(call(y0, 0.5, xs))
     ref = y0
     for i in range(n_levels):
@@ -98,7 +98,7 @@ def test_lookup_chain_pallas_matches_python_loop_bitwise():
     assert cache.compiles == 1
     # warm re-resolution: same signature, zero recompiles
     again = cache.lookup_chain_pallas(scan_step, layout, n_levels, 0,
-                                      [y0, 0.5, xs])
+                                      [y0, 0.5, xs], interpret=True)
     np.testing.assert_array_equal(np.asarray(again(y0, 0.5, xs)), out)
     assert cache.compiles == 1
 
@@ -112,12 +112,41 @@ def test_lookup_chain_pallas_dot_body():
     b = jnp.asarray(rng.normal(size=(4, 4)), jnp.float32)
     layout = ("single", "single", "single")
     call = cache.lookup_chain_pallas(gemm_tile, layout, n_levels, 0,
-                                     [c0, a, b])
+                                     [c0, a, b], interpret=True)
     out = np.asarray(call(c0, a, b))
     ref = c0
     for _ in range(n_levels):
         ref = gemm_tile(ref, a, b)
     np.testing.assert_array_equal(out, np.asarray(ref))
+
+
+def test_lookup_chain_pallas_row_blocks_bitwise():
+    """Carries taller than one row block run on a (row blocks, levels)
+    grid: ewise bodies block every operand, dot bodies block the carry and
+    its row-aligned operand and read the contraction partner whole — and
+    stay bitwise equal to the python loop."""
+    from repro.core.executable_cache import _MAX_BLOCK_ROWS
+    rows, n_levels = 2 * _MAX_BLOCK_ROWS, 3
+    rng = np.random.default_rng(5)
+    y0 = jnp.asarray(rng.normal(size=(rows, 8)), jnp.float32)
+    xs = jnp.asarray(rng.normal(size=(n_levels, rows, 8)), jnp.float32)
+    cs = jnp.asarray([0.5, 0.25, 2.0], jnp.float32)
+    b = jnp.asarray(rng.normal(size=(8, 8)), jnp.float32)
+    cache = bind.ExecutableCache()
+    scan = cache.lookup_chain_pallas(scan_step, ("single", "xs_const", "xs"),
+                                     n_levels, 0, [y0, cs, xs],
+                                     interpret=True)
+    dot = cache.lookup_chain_pallas(gemm_tile, ("single", "xs", "single"),
+                                    n_levels, 0, [y0, xs, b],
+                                    interpret=True)
+    ref_scan = ref_dot = y0
+    for i in range(n_levels):
+        ref_scan = scan_step(ref_scan, cs[i], xs[i])
+        ref_dot = gemm_tile(ref_dot, xs[i], b)
+    np.testing.assert_array_equal(np.asarray(scan(y0, cs, xs)),
+                                  np.asarray(ref_scan))
+    np.testing.assert_array_equal(np.asarray(dot(y0, xs, b)),
+                                  np.asarray(ref_dot))
 
 
 # ---------------------------------------------------------------------------
@@ -144,7 +173,36 @@ def test_pallas_chain_one_executable_per_chain():
     assert mb.pallas_chains_dispatched == 1
     assert mb.ops_pallas == 8
     assert cache.compiles == 1          # ONE compiled executable
-    assert not mb._no_pallas
+    assert mb.interpret                 # CPU host: Pallas interpret mode
+
+
+def test_mesh_backend_interprets_on_cpu():
+    """The Pallas mode follows the platform: interpreted on the CPU, and
+    ``pallas="auto"`` arms chain lowering on one CPU device only when a
+    mesh exists (on a TPU it is armed on one chip too)."""
+    mb = MeshBackend()
+    assert mb._devices[0].platform == "cpu"
+    assert mb.interpret
+    assert mb._pallas_enabled() == (len(jax.devices()) >= 2)
+    assert MeshBackend(pallas=True)._pallas_enabled()
+    assert not MeshBackend(pallas=False)._pallas_enabled()
+
+
+def test_pallas_lowering_failure_raises(monkeypatch):
+    """A kernel-tagged chain whose Pallas build fails must fail the flush,
+    not quietly pin the fn to another path."""
+    from repro.core import executable_cache
+
+    def broken(*_a, **_k):
+        raise RuntimeError("forced pallas lowering failure")
+
+    monkeypatch.setattr(executable_cache, "chain_pallas_call", broken)
+    mb = MeshBackend(pallas=True)
+    with pytest.raises(RuntimeError, match="forced pallas lowering"):
+        _chain_workflow(mb, scan_step, cache=bind.ExecutableCache())
+    assert mb.pallas_chains_dispatched == 0
+    assert mb.chains_dispatched == 0
+    assert scan_step not in mb._no_chain     # nothing pinned
 
 
 def test_untagged_body_falls_back_to_generic_scan():

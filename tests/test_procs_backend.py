@@ -47,6 +47,12 @@ def _hang_step(c: bind.InOut, s: bind.In):
     return c * 1.01 + s
 
 
+@bind.op
+def _mark_cpu_only(c: bind.InOut):
+    # 1 where the executing process was started with JAX_PLATFORMS=cpu
+    return c + float(os.environ.get("JAX_PLATFORMS") == "cpu")
+
+
 def _chains(wf, arrs, depth, mix_at=(), step=_step):
     n = len(arrs)
     for lv in range(depth):
@@ -104,6 +110,23 @@ def test_procs_jax_payload_roundtrip():
     for a, b in zip(ref, vals):
         np.testing.assert_allclose(a, b, rtol=0, atol=0)
         assert a.dtype == b.dtype
+
+
+def test_workers_start_with_cpu_only_jax(monkeypatch):
+    """Pool workers must never claim an accelerator the parent holds: they
+    start with ``JAX_PLATFORMS=cpu`` whatever the parent's environment."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    n = 4           # a rank count no other test here uses: a fresh pool
+
+    def build(wf, arrs):
+        for r, a in enumerate(arrs):
+            with bind.node(r):
+                _mark_cpu_only(a)
+
+    vals, _, _ = _run(build, n, backend="procs")
+    for r, v in enumerate(vals):
+        np.testing.assert_array_equal(v, np.arange(8.0) + r + 1.0)
+    assert "JAX_PLATFORMS" not in os.environ    # the parent's env restored
 
 
 def test_fetch_is_zero_copy_shm_view():
