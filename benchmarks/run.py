@@ -1,4 +1,4 @@
-"""Benchmark harness: one module per paper table/figure + the roofline.
+"""Benchmark harness: one module per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run                     # all
     PYTHONPATH=src python -m benchmarks.run strassen            # one
@@ -28,7 +28,7 @@ def main() -> None:
     enable_compile_cache()
     from benchmarks import (
         bench_strassen, bench_distgemm, bench_sort, bench_dag_overhead,
-        bench_roofline, bench_serving)
+        bench_serving)
 
     args = [a for a in sys.argv[1:] if a != "--quick"]
     quick = "--quick" in sys.argv[1:]
@@ -38,7 +38,6 @@ def main() -> None:
         "sort": lambda: bench_sort.run(n_items=100_000 if quick else 1_000_000),
         "dag_overhead": lambda: bench_dag_overhead.run(quick=quick),
         "serving": lambda: bench_serving.run(quick=quick),
-        "roofline": lambda: bench_roofline.run(mesh=None),
     }
     if args and "all" not in args:
         # several names combine into one run (and one results file) —

@@ -23,7 +23,7 @@ event stream stays byte-identical across backends.
 
 from __future__ import annotations
 
-from ..stats import TransferEvent, _nbytes
+from ..stats import Span, TransferEvent, _nbytes
 
 
 class RankFailure(RuntimeError):
@@ -258,6 +258,18 @@ def materialize(payload):
     return payload
 
 
+def materialize_rows(payloads: list) -> list:
+    """``payloads`` with every lazy row concretised, in one ``bind.rows``
+    span (no span where none is lazy)."""
+    n = sum(type(a) is BatchSlice for a in payloads)
+    if not n:
+        return payloads
+    with Span("bind.rows") as sp:
+        out = [materialize(a) for a in payloads]
+        sp.rows = n
+    return out
+
+
 def drop_versions(gc_keys, stores, where, key_bytes, live_b, live_c):
     """Apply an op's GC drop list; returns updated ``(live_bytes, live_c)``.
 
@@ -297,33 +309,36 @@ def spill_dead_buckets(ex) -> int:
     seam-crossing chain stays lazy.  Returns the number of rows spilled.
     """
     buckets = ex._lazy_buckets
-    if not buckets:
+    if not any(0 < len(b.live) < b.n for b in buckets):
+        for bucket in [b for b in buckets if len(b.live) != b.n]:
+            buckets.discard(bucket)         # fully dead: just leaves
         return 0
     stores, where = ex._stores, ex._where
-    spilled = 0
-    for bucket in list(buckets):
-        live = bucket.live
-        if len(live) == bucket.n:       # untouched: stays one lazy buffer
-            continue
-        if live:
-            buffer = bucket.buffer
-            for idx in sorted(live):
-                vkey = bucket.rows.get(idx)
-                ranks = where.get(vkey) if vkey is not None else None
-                if not ranks:
-                    continue
-                concrete = None
-                for r in ranks:
-                    payload = stores[r].get(vkey)
-                    if type(payload) is BatchSlice and payload.bucket is bucket:
-                        if concrete is None:
-                            concrete = buffer[idx]
-                        stores[r][vkey] = concrete
-                if concrete is not None:
-                    spilled += 1
-            live.clear()
-        buckets.discard(bucket)
-    return spilled
+    with Span("bind.rows") as sp:
+        for bucket in list(buckets):
+            live = bucket.live
+            if len(live) == bucket.n:   # untouched: stays one lazy buffer
+                continue
+            if live:
+                buffer = bucket.buffer
+                for idx in sorted(live):
+                    vkey = bucket.rows.get(idx)
+                    ranks = where.get(vkey) if vkey is not None else None
+                    if not ranks:
+                        continue
+                    concrete = None
+                    for r in ranks:
+                        payload = stores[r].get(vkey)
+                        if (type(payload) is BatchSlice
+                                and payload.bucket is bucket):
+                            if concrete is None:
+                                concrete = buffer[idx]
+                            stores[r][vkey] = concrete
+                    if concrete is not None:
+                        sp.rows += 1
+                live.clear()
+            buckets.discard(bucket)
+    return sp.rows
 
 
 def apply_ships(ex, p) -> None:

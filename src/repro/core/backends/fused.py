@@ -75,10 +75,10 @@ import math
 import jax
 import numpy as np
 
-from ..stats import _nbytes
+from ..stats import Span, _nbytes
 from .base import (Backend, BatchBucket, BatchSlice, apply_ships, commit,
-                   drop_versions, gather_args, materialize, resolve_call,
-                   spill_dead_buckets)
+                   drop_versions, gather_args, materialize_rows,
+                   resolve_call, spill_dead_buckets)
 from .serial import SerialPlanBackend
 
 _PENDING = object()     # "not produced by a fused bucket" sentinel
@@ -235,8 +235,9 @@ class FusedBatchBackend(Backend):
     def _apply_ships(self, ex, p) -> None:
         """Concretise and replay one op's ship schedule (override point:
         the mesh backend lowers this onto device collectives)."""
-        self._materialize_shipped(ex, p)
-        apply_ships(ex, p)
+        with Span("bind.ship"):
+            self._materialize_shipped(ex, p)
+            apply_ships(ex, p)
 
     def execute(self, ex, wf, plan) -> None:
         min_chain = self.min_chain_levels
@@ -262,14 +263,18 @@ class FusedBatchBackend(Backend):
                 inj.check(ex, ex._wavefront_base + li, level=li)
             chain = chain_at.get(li) if chain_at else None
             if (chain is not None and chain.n_levels >= min_chain
-                    and chain.fn not in self._no_chain
-                    and self._run_chain(ex, ops, plan, chain)):
-                spill_dead_buckets(ex)
-                li += chain.n_levels
-                continue
+                    and chain.fn not in self._no_chain):
+                with Span("bind.chain"):
+                    ran = self._run_chain(ex, ops, plan, chain)
+                    if ran:
+                        spill_dead_buckets(ex)
+                if ran:
+                    li += chain.n_levels
+                    continue
             lo, hi = levels[li]
-            self._run_level(ex, ops, schedule, lo, hi, groups[li])
-            spill_dead_buckets(ex)
+            with Span("bind.level"):
+                self._run_level(ex, ops, schedule, lo, hi, groups[li])
+                spill_dead_buckets(ex)
             li += 1
 
     # -- per-level fused dispatch ---------------------------------------------
@@ -311,8 +316,7 @@ class FusedBatchBackend(Backend):
         for off, (p, node, args) in enumerate(staged):
             result = results[off]
             if result is _PENDING:
-                if any(type(a) is BatchSlice for a in args):
-                    args = [materialize(a) for a in args]
+                args = materialize_rows(args)
                 result = resolve_call(ex, p, args)(*args)
             if p.simple_write and not isinstance(result, tuple):
                 wk = p.write_keys[0]
@@ -351,13 +355,19 @@ class FusedBatchBackend(Backend):
 
     def _materialize_shipped(self, ex, p) -> None:
         """Concretise lazy slices about to travel (boundary: transfers)."""
-        for vkey, root, _transfers in p.ships:
-            payload = ex._stores[root][vkey]
-            if type(payload) is BatchSlice:
-                concrete = payload.materialize()
-                payload.release()
-                for r in ex._where[vkey]:
-                    ex._stores[r][vkey] = concrete
+        stores = ex._stores
+        if not any(type(stores[root][vkey]) is BatchSlice
+                   for vkey, root, _transfers in p.ships):
+            return
+        with Span("bind.rows") as sp:
+            for vkey, root, _transfers in p.ships:
+                payload = stores[root][vkey]
+                if type(payload) is BatchSlice:
+                    concrete = payload.materialize()
+                    payload.release()
+                    for r in ex._where[vkey]:
+                        stores[r][vkey] = concrete
+                    sp.rows += 1
 
     def _run_bucket(self, ex, staged, members, results, result_nbytes) -> None:
         p0, _node0, args0 = staged[members[0]]
@@ -384,7 +394,7 @@ class FusedBatchBackend(Backend):
                 call_args.append(buf)
                 sig_args.append(buf)
             else:
-                column = [materialize(a) for a in column]
+                column = materialize_rows(column)
                 layout.append(FLAT)
                 call_args.extend(column)
                 sig_args.append(column[0])
@@ -444,12 +454,12 @@ class FusedBatchBackend(Backend):
         if self._uniform_jax_aval(column) is None:
             return None
         if len(column) == 1:
-            a = materialize(column[0])
+            a, = materialize_rows(column)
             return SINGLE, [a], a
         buf = _common_buffer(column)
         if buf is not None:
             return STACKED, [buf], buf
-        concrete = [materialize(a) for a in column]
+        concrete = materialize_rows(column)
         return FLAT, concrete, concrete[0]
 
     def _dispatch_chain(self, ex, chain, layout, width, n_levels, carry_pos,
@@ -626,7 +636,7 @@ class FusedBatchBackend(Backend):
                         (n_levels, width) + buf.shape[1:]))
                     self.xs_passthrough += 1
                 else:
-                    flat = [materialize(a) for a in flat_grid]
+                    flat = materialize_rows(flat_grid)
                     stacked = jax.numpy.stack(flat)
                     if width > 1:
                         stacked = stacked.reshape(

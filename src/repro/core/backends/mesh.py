@@ -63,7 +63,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..lowering import broadcast_by_schedule, schedule_for_topology
-from ..stats import TransferEvent, _nbytes
+from ..stats import Span, TransferEvent, _nbytes
 from .base import BatchSlice
 from .fused import CONST, SINGLE, XS, XS_CONST, FusedBatchBackend
 
@@ -177,11 +177,12 @@ class MeshBackend(FusedBatchBackend):
             sched, arity = self._schedule_eff, self._arity
             spec = P("r", *(None,) * len(shape))
 
-            def body(x):
+            # named for the trace: its module is ``jit_bind_ship``
+            def bind_ship(x):
                 return broadcast_by_schedule(x, sched, "r", root=root,
                                              arity=arity)
 
-            smapped = jax.shard_map(body, mesh=mesh, in_specs=spec,
+            smapped = jax.shard_map(bind_ship, mesh=mesh, in_specs=spec,
                                     out_specs=spec, check_vma=False)
             call = (jax.jit(smapped), mesh, spec)
             self._bcast_cache[key] = call
@@ -206,34 +207,35 @@ class MeshBackend(FusedBatchBackend):
         if not self._active:
             super()._apply_ships(ex, p)
             return
-        self._materialize_shipped(ex, p)
-        n = ex.n_nodes
-        stores, where = ex._stores, ex._where
-        events = ex._stats.transfers
-        base_round = ex._round_counter
-        wavefront = ex._wavefront_base + p.level - 1
-        for vkey, root, transfers in p.ships:
-            payload = stores[root][vkey]
-            rows = None
-            if isinstance(payload, jax.Array) and payload.size:
-                rows = self._broadcast_rows(payload, root, n)
-            if rows is None:
-                self.ships_simulated += 1
-            else:
-                self.ships_lowered += 1
-            # virtual replay: the plan's precomputed transfer schedule is
-            # emitted verbatim (byte-identical stream); only the payload a
-            # destination rank holds differs — its own broadcast row
-            nb = _nbytes(payload)
-            ranks = where[vkey]
-            for src, dst, kind, rel in transfers:
-                stores[dst][vkey] = (payload if rows is None
-                                     else rows[dst][0])
-                ranks.add(dst)
-                ex._live_entries += 1
-                events.append(
-                    TransferEvent(vkey, src, dst, nb, base_round + rel,
-                                  kind, wavefront))
+        with Span("bind.ship"):
+            self._materialize_shipped(ex, p)
+            n = ex.n_nodes
+            stores, where = ex._stores, ex._where
+            events = ex._stats.transfers
+            base_round = ex._round_counter
+            wavefront = ex._wavefront_base + p.level - 1
+            for vkey, root, transfers in p.ships:
+                payload = stores[root][vkey]
+                rows = None
+                if isinstance(payload, jax.Array) and payload.size:
+                    rows = self._broadcast_rows(payload, root, n)
+                if rows is None:
+                    self.ships_simulated += 1
+                else:
+                    self.ships_lowered += 1
+                # virtual replay: the plan's precomputed transfer schedule is
+                # emitted verbatim (byte-identical stream); only the payload a
+                # destination rank holds differs — its own broadcast row
+                nb = _nbytes(payload)
+                ranks = where[vkey]
+                for src, dst, kind, rel in transfers:
+                    stores[dst][vkey] = (payload if rows is None
+                                         else rows[dst][0])
+                    ranks.add(dst)
+                    ex._live_entries += 1
+                    events.append(
+                        TransferEvent(vkey, src, dst, nb, base_round + rel,
+                                      kind, wavefront))
 
     # -- rank-local fusion while payloads are placed ----------------------------
     def _run_bucket(self, ex, staged, members, results, result_nbytes) -> None:

@@ -28,6 +28,14 @@ import jax
 import numpy as np
 
 
+def _named(call: Callable, kind: str, fn: Callable) -> Callable:
+    """``call`` renamed ``<kind>_<fn's name>``: its executable is then
+    ``jit_<kind>_<name>`` in a profiler trace."""
+    call.__name__ = call.__qualname__ = (
+        f"{kind}_{getattr(fn, '__name__', 'fn')}")
+    return call
+
+
 def _abstract(arg: Any):
     """Abstract signature component of one payload: shape/dtype or type.
 
@@ -160,7 +168,7 @@ class ExecutableCache:
                     out = out[0]    # fused ops write exactly one payload
                 return out
 
-            return jax.jit(stacked_call)
+            return jax.jit(_named(stacked_call, "bind_bucket", fn))
 
         return self._resolve(key, build)
 
@@ -236,7 +244,7 @@ class ExecutableCache:
                                         length=n_levels)
                 return final
 
-            return jax.jit(chain_call)
+            return jax.jit(_named(chain_call, "bind_chain", fn))
 
         return self._resolve(key, build)
 
@@ -458,7 +466,8 @@ def chain_pallas_call(fn: Callable, layout: tuple, n_levels: int,
             name=f"bind_chain_{getattr(fn, '__name__', 'fn')}",
         )(*(flat[p] for p in tensor_pos))
 
-    return jax.jit(chain_call, static_argnums=const_pos)
+    return jax.jit(_named(chain_call, "bind_chain", fn),
+                   static_argnums=const_pos)
 
 
 # Process-wide cache: signatures are shared across executors and workflows

@@ -73,7 +73,7 @@ from .program import (PROGRAM_CACHE_STATS, Segment, probe_plan, resolve_plan)
 from .shm_store import ShmRef
 from .recovery import (apply_failure, build_subset_plan, choose_replacement,
                        plan_recovery, wipe_rank)
-from .stats import ExecutionStats, TransferEvent, _nbytes
+from .stats import ExecutionStats, Span, TransferEvent, _nbytes
 from .trace import OpNode, Workflow
 
 __all__ = ["ExecutionStats", "TransferEvent", "LocalExecutor"]
@@ -343,7 +343,9 @@ class LocalExecutor:
                 raise KeyError(f"no payload for {version!r}")
             payload = self._stores[next(iter(ranks))][version.key]
             if type(payload) is BatchSlice:
-                concrete = payload.materialize()
+                with Span("bind.rows") as sp:
+                    concrete = payload.materialize()
+                    sp.rows = 1
                 payload.release()
                 self._stats.fetch_bytes_copied += _nbytes(concrete)
                 for r in ranks:
@@ -525,16 +527,21 @@ class LocalExecutor:
         # implicit.  Only items recorded since the last placement are new.
         if self._init_seen < upto:
             rm = self._rank_map
-            for vkey, (payload, rank) in islice(
-                    wf.initial.items(), self._init_seen, upto):
-                if vkey not in self._where:
-                    if rm:
-                        rank = rm.get(rank, rank)
-                    self._place(rank, vkey,
-                                self.backend.place(self, rank, payload))
+            with Span("bind.plan"):
+                for vkey, (payload, rank) in islice(
+                        wf.initial.items(), self._init_seen, upto):
+                    if vkey not in self._where:
+                        if rm:
+                            rank = rm.get(rank, rank)
+                        self._place(rank, vkey,
+                                    self.backend.place(self, rank, payload))
             self._init_seen = upto
 
     def _flush(self) -> ExecutionStats:
+        with Span("bind.flush"):
+            return self._flush_pending()
+
+    def _flush_pending(self) -> ExecutionStats:
         pending, self._pending = self._pending, []
         wf = self._wf
         # the workflow reference only serves the pending trace — dropping
@@ -702,9 +709,10 @@ class LocalExecutor:
             for b in reversed(bounds):              # largest range first
                 if b <= pos:
                     break
-                p = probe_plan(wf, pos, b, self.n_nodes,
-                               self.collective_mode, self._where,
-                               pin_of[b], rank_map=self._rank_map)
+                with Span("bind.plan"):
+                    p = probe_plan(wf, pos, b, self.n_nodes,
+                                   self.collective_mode, self._where,
+                                   pin_of[b], rank_map=self._rank_map)
                 if p is not None:
                     plan, nxt = p, b
                     break
@@ -723,10 +731,12 @@ class LocalExecutor:
                 later = [b for b in bounds if b > pos]
                 if len(later) > 1:
                     for lo, hi in zip(later, later[1:]):
-                        if probe_plan(wf, lo, hi, self.n_nodes,
-                                      self.collective_mode, self._where,
-                                      pin_of[hi],
-                                      rank_map=self._rank_map) is not None:
+                        with Span("bind.plan"):
+                            hit = probe_plan(wf, lo, hi, self.n_nodes,
+                                             self.collective_mode,
+                                             self._where, pin_of[hi],
+                                             rank_map=self._rank_map)
+                        if hit is not None:
                             nxt = later[0]
                             break
                 self._run_planned(wf, pos, nxt, pin_of[nxt])
@@ -736,9 +746,12 @@ class LocalExecutor:
     def _run_planned(self, wf: Workflow, start: int, end: int,
                      pinned: set, preplan=None) -> ExecutionStats:
         stats = self._stats
-        current = preplan if preplan is not None else resolve_plan(
-            wf, start, end, self.n_nodes, self.collective_mode, self._where,
-            pinned, rank_map=self._rank_map)
+        current = preplan
+        if current is None:
+            with Span("bind.plan"):
+                current = resolve_plan(
+                    wf, start, end, self.n_nodes, self.collective_mode,
+                    self._where, pinned, rank_map=self._rank_map)
         while current is not None:
             base_round = self._round_counter
             self._wavefront_base = len(stats.wavefronts)

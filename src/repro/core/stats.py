@@ -14,12 +14,22 @@ in seconds, not just message counts.  Transfers carry the global wavefront
 ordinal they precede, so the default *contention-aware* makespan overlaps
 each level's communication with its compute (``max(comm, compute)`` per
 level); ``overlap=False`` keeps the legacy summed model for A/B comparison.
+
+:class:`Span` times the executor's host work in named parts (planning,
+level dispatch, ships, row materialisation, fetch), with self time net of
+the spans nested inside, into :data:`SPAN_HISTORY`; each span is also a
+profiler annotation of the same name.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import threading
+import time
 from typing import Any
+
+from jax.profiler import TraceAnnotation
 
 
 def _nbytes(x: Any) -> int:
@@ -95,7 +105,6 @@ class ExecutionStats:
     # this counter is a dispatch-overhead regression.  Not part of the
     # cross-backend conformance contract (simulated backends leave it 0).
     control_messages: int = 0
-
     @property
     def recompute_ratio(self) -> float:
         """Fraction of executed ops that were lineage-recovery recomputation.
@@ -202,6 +211,68 @@ class ExecutionStats:
             f = flops[w] / rate if rate > 0.0 and w < len(flops) else 0.0
             total += c if c >= f else f
         return total + self.critical_path * op_time_s
+
+
+_OPEN = threading.local()       # per thread: the stack of open spans
+# The process's most recent finished span trees (an outermost span and
+# every span inside it), newest last, as ``(start, end, self_s, rows)``:
+# ``time.perf_counter`` readings, each span name's summed self seconds in
+# the tree, and the lazy rows materialised in it.  Bounded, so a
+# long-lived process keeps only its latest trees.
+SPAN_HISTORY: collections.deque = collections.deque(maxlen=4096)
+
+
+class Span:
+    """``with Span(name) as sp:`` times one part of the host's work into
+    the open span tree and emits a ``jax.profiler.TraceAnnotation`` of that
+    name (a no-op check while no profiler records).
+
+    Spans nest per thread: a span's self time is its duration less that of
+    the spans closed inside it, so the self times of a tree add up to its
+    outermost span's duration.  ``sp.rows`` counts the lazy rows the span
+    materialised.  The outermost span appends its tree to
+    :data:`SPAN_HISTORY` on exit, raising or not: the time was spent.
+    """
+
+    __slots__ = ("name", "rows", "_start", "_child_s", "_tree",
+                 "_tree_rows", "_annotation")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.rows = 0
+
+    def __enter__(self) -> "Span":
+        stack = getattr(_OPEN, "stack", None)
+        if stack is None:
+            stack = _OPEN.stack = []
+        if not stack:               # the outermost span keeps the tree's sums
+            self._tree: dict = {}
+            self._tree_rows = 0
+        stack.append(self)
+        self._child_s = 0.0
+        if TraceAnnotation.is_enabled():    # a profiler is recording
+            self._annotation = TraceAnnotation(self.name)
+            self._annotation.__enter__()
+        else:
+            self._annotation = None
+        self._start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        stack = _OPEN.stack
+        stack.pop()
+        total = end - self._start
+        if stack:
+            stack[-1]._child_s += total
+        root = stack[0] if stack else self
+        tree = root._tree
+        tree[self.name] = tree.get(self.name, 0.0) + total - self._child_s
+        root._tree_rows += self.rows
+        if not stack:
+            SPAN_HISTORY.append((self._start, end, tree, self._tree_rows))
 
 
 class LatencyStats:

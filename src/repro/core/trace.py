@@ -24,6 +24,7 @@ import itertools
 import threading
 from typing import Any, Callable, Optional, Sequence
 
+from .stats import Span
 from .versioning import Ref, Version, reset_ids
 
 
@@ -489,8 +490,9 @@ class Workflow:
 
     def fetch(self, arr: BindArray) -> Any:
         """Read back the head payload of an array (implies sync)."""
-        self.sync()
-        return self._executor.value(arr.ref.head)
+        with Span("bind.fetch"):
+            self.sync()
+            return self._executor.value(arr.ref.head)
 
     def checkpoint(self, arrays: Sequence[BindArray], manager,
                    step: Optional[int] = None, name: str = "ckpt"):

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import core as bind
+from repro.core.stats import Span
 
 
 # -- tile-level ops (the leaves of the DAG) -----------------------------------
@@ -208,11 +209,13 @@ class Tiled(TileView):
         devices are copied there)."""
         grid = [[self.wf.fetch(self.tile(i, j)) for j in range(self.nt)]
                 for i in range(self.mt)]
-        if self.xp is np:
-            return np.block([[np.asarray(t) for t in row] for row in grid])
-        dev = next(iter(grid[0][0].devices()))
-        return jnp.block([[jax.device_put(t, dev) for t in row]
-                          for row in grid])
+        with Span("bind.assemble"):
+            if self.xp is np:
+                return np.block([[np.asarray(t) for t in row]
+                                 for row in grid])
+            dev = next(iter(grid[0][0].devices()))
+            return jnp.block([[jax.device_put(t, dev) for t in row]
+                              for row in grid])
 
 
 def gemm_tiles(a: TileView, b: TileView, c: TileView) -> None:
