@@ -15,8 +15,13 @@ member op's payload is a lazy :class:`BatchSlice` view into it.  When the
 next level's bucket consumes exactly those members (the ubiquitous
 chain-of-wavefronts shape), the whole buffer is passed through as ONE
 argument and returned as ONE result — a level of N ops costs one dispatch
-and two buffers instead of ~3N.  Slices materialise only at the boundaries:
-a non-fused consumer, a transfer, or a user ``fetch()``.
+and two buffers instead of ~3N.  A bucket that reads lazy rows in any other
+order or mix (a subset, a permutation, rows of several buffers, rows beside
+concrete payloads) reads them inside its own executable: each source buffer
+goes in once, with each member's ``(source, row)`` as a runtime operand, so
+buckets picking different rows from sources of the same shapes share the
+executable.  Slices materialise only at the boundaries: a non-fused
+consumer, a transfer, a spill, or a user ``fetch()``.
 
 **Chain fusion** goes one step further: when the plan detects a
 :class:`~repro.core.plan.ChainSlice` — consecutive levels of one signature
@@ -86,6 +91,10 @@ _PENDING = object()     # "not produced by a fused bucket" sentinel
 # per-position layouts of a batched/chained executable's flat argument list
 FLAT = "flat"           # n_batch consecutive member payloads, stacked inside
 STACKED = "stacked"     # one pre-stacked buffer (batched residency pass-through)
+ROWS = "rows"           # lazy rows read inside the executable, as (ROWS,
+                        # n_buffers, n_payloads): the column's source buffers,
+                        # its concrete payloads, then each member's (source,
+                        # row) as an int32 array
 CONST = "const"         # one shared constant, broadcast by vmap
 SINGLE = "single"       # one array: a width-1 chain's carry or exterior
 XS = "xs"               # per-level varying exterior payloads, pre-stacked
@@ -182,6 +191,11 @@ class FusedBatchBackend(Backend):
         # varying-exterior xs grids served straight from a fused bucket's
         # stacked buffer (no per-row materialise + restack)
         self.xs_passthrough = 0
+        # lazy rows a bucket read from their buffers inside its executable,
+        # and bucket columns whose rows were concretised instead because a
+        # source buffer was not where the bucket executes
+        self.rows_gathered = 0
+        self.columns_unplaced = 0
 
     def _probe_payload(self, ex, k):
         """Version ``k``'s resident payload, or None if not yet
@@ -369,6 +383,44 @@ class FusedBatchBackend(Backend):
                         stores[r][vkey] = concrete
                     sp.rows += 1
 
+    def _reads_in_place(self, p, buffer) -> bool:
+        """Whether ``p``'s bucket executable may read rows of ``buffer``
+        where it lies (override point: the mesh backend asks for the
+        executing rank's device)."""
+        return True
+
+    def _row_column(self, p, column):
+        """``(buffers, payloads, picks)`` of a bucket argument column
+        holding lazy rows; None when it holds none, or when a source buffer
+        is not where ``p`` executes.
+
+        ``buffers`` are the distinct source buffers in first-seen order and
+        ``payloads`` the concrete members in order; ``picks[j]`` is member
+        ``j``'s ``(source, row)``: a row of ``buffers[source]``, or the
+        payload ``payloads[row]`` where ``source`` is ``len(buffers)``.
+        """
+        slot: dict[int, int] = {}
+        buffers, payloads, picks = [], [], []
+        for a in column:
+            if type(a) is BatchSlice:
+                buf = a.buffer
+                s = slot.get(id(buf))
+                if s is None:
+                    if not self._reads_in_place(p, buf):
+                        self.columns_unplaced += 1
+                        return None
+                    s = slot[id(buf)] = len(buffers)
+                    buffers.append(buf)
+                picks.append((s, a.index))
+            else:
+                picks.append((-1, len(payloads)))
+                payloads.append(a)
+        if not buffers:
+            return None
+        picks = np.array(picks, np.int32)
+        picks[picks[:, 0] < 0, 0] = len(buffers)
+        return buffers, payloads, picks
+
     def _run_bucket(self, ex, staged, members, results, result_nbytes) -> None:
         p0, _node0, args0 = staged[members[0]]
         if p0.fn in self._no_fuse:
@@ -377,10 +429,12 @@ class FusedBatchBackend(Backend):
             return
         n = len(members)
         # flat layout (see ExecutableCache.lookup_vmapped): pass a chained
-        # bucket's stacked buffer through whole; otherwise n member payloads
+        # bucket's stacked buffer through whole; read lazy rows from their
+        # buffers inside the executable; otherwise n member payloads
         layout = []
         call_args = []
         sig_args = []
+        gathered = 0
         for i, k in enumerate(p0.arg_keys):
             if k is None:
                 layout.append(CONST)
@@ -393,6 +447,16 @@ class FusedBatchBackend(Backend):
                 layout.append(STACKED)
                 call_args.append(buf)
                 sig_args.append(buf)
+                continue
+            rows = self._row_column(p0, column)
+            if rows is not None:
+                buffers, payloads, picks = rows
+                layout.append((ROWS, len(buffers), len(payloads)))
+                call_args.extend(buffers)
+                call_args.extend(payloads)
+                call_args.append(picks)
+                sig_args.extend(buffers)
+                gathered += n - len(payloads)
             else:
                 column = materialize_rows(column)
                 layout.append(FLAT)
@@ -411,6 +475,7 @@ class FusedBatchBackend(Backend):
             return
         self.batches_dispatched += 1
         self.ops_fused += n
+        self.rows_gathered += gathered
         # batched residency: one stacked buffer, n lazy row views
         elt_aval = out.aval.update(shape=out.shape[1:])
         nb = int(out.nbytes) // n       # one shape/dtype per bucket

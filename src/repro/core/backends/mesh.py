@@ -26,9 +26,11 @@ in tests/CI; on TPU the identical build runs un-interpreted):
 * **Placement** — while ship lowering is active, rank ``r``'s initial
   payloads are put on ``devices[r]`` and a shipped row lands on the
   destination's device, so rank ``r``'s op bodies run on chip ``r``.
-  Fused buckets never mix ranks, and a chain dispatches only when it runs
-  on one rank with no first-level ship (its operands then already sit on
-  that rank's device).
+  Fused buckets never mix ranks, and read lazy rows inside their
+  executable only from buffers on their rank's device (a column with a row
+  elsewhere is concretised first, counted in ``columns_unplaced``); a chain
+  dispatches only when it runs on one rank with no first-level ship (its
+  operands then already sit on that rank's device).
 
 The frontend contract is unchanged: commit/GC/transfer accounting is
 replayed virtually in plan order (the procs-backend pattern), so values,
@@ -250,6 +252,10 @@ class MeshBackend(FusedBatchBackend):
             if len(group) >= self.min_batch:
                 super()._run_bucket(ex, staged, group, results,
                                     result_nbytes)
+
+    def _reads_in_place(self, p, buffer) -> bool:
+        return (not self._active
+                or buffer.devices() == {self._devices[p.exec_ranks[0]]})
 
     def _run_chain(self, ex, ops, plan, chain) -> bool:
         if self._active:

@@ -21,6 +21,7 @@ permanently.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Callable
 
@@ -50,6 +51,30 @@ def _abstract(arg: Any):
     if shape is not None and dtype is not None:
         return (shape, dtype, isinstance(arg, jax.Array))
     return t
+
+
+def _read_rows(n_buffers: int, n_payloads: int, flat: tuple, pos: int):
+    """The stacked column of a ``("rows", n_buffers, n_payloads)`` position
+    whose operands start at ``flat[pos]`` (see
+    :meth:`ExecutableCache.lookup_vmapped`), and the position after them.
+
+    One loop over the members copies each row out of its source, so the
+    trace (and the executable's set-up in every process) grows with the
+    sources, not with the members, and no source is copied whole.
+    """
+    end = pos + n_buffers + n_payloads
+    sources = list(flat[pos:pos + n_buffers])
+    if n_payloads:
+        sources.append(jax.numpy.stack(flat[pos + n_buffers:end]))
+    rows = [functools.partial(jax.lax.dynamic_index_in_dim, source,
+                              keepdims=False) for source in sources]
+    if len(rows) == 1:
+        def member(pick):
+            return rows[0](pick[1])
+    else:
+        def member(pick):
+            return jax.lax.switch(pick[0], rows, pick[1])
+    return jax.lax.map(member, flat[end]), end + 1
 
 
 MAX_ENTRIES = 1024
@@ -136,41 +161,28 @@ class ExecutableCache:
         ``"flat"`` — ``n_batch`` consecutive member payloads, stacked inside
         the jitted body; ``"stacked"`` — one pre-stacked buffer passed
         through whole (the fused backend's batched-residency fast path);
+        ``("rows", n_buffers, n_payloads)`` — member rows read inside the
+        jitted body: ``n_buffers`` stacked source buffers, ``n_payloads``
+        concrete member payloads, then an int32 ``(n_batch, 2)`` array
+        whose row ``j`` is ``(source, row)``: member ``j`` is that row of
+        that buffer, or, where ``source`` is ``n_buffers``, that payload;
         ``"const"`` — one shared constant, broadcast by vmap.  The entry
         runs ``vmap(fn)`` over the batch and returns the **stacked** result
         buffer — callers keep per-member rows as lazy views, so a fused
         level costs one dispatch and one result buffer, not N.
 
         ``sig_args`` holds one representative per position (first member
-        payload / buffer / constant); constants stay call arguments, so
-        buckets differing only in constant *values* share the executable.
+        payload / buffer / constant), and every source buffer of a
+        ``"rows"`` position; constants and picks stay call arguments, so
+        buckets differing only in constant *values* or in the rows they
+        pick share the executable.
 
         Tracing failures are the caller's problem (it falls back to per-op
         dispatch and should stop requesting batches for that ``fn``); the
         entry is evicted so a broken executable is never replayed.
         """
         key = (fn, layout, n_batch) + tuple(_abstract(a) for a in sig_args)
-        in_axes = tuple(None if lay == "const" else 0 for lay in layout)
-
-        def build():
-            def stacked_call(*flat):
-                args = []
-                pos = 0
-                for lay in layout:
-                    if lay == "flat":
-                        args.append(jax.numpy.stack(flat[pos:pos + n_batch]))
-                        pos += n_batch
-                    else:           # "stacked" buffer or "const"
-                        args.append(flat[pos])
-                        pos += 1
-                out = jax.vmap(fn, in_axes=in_axes)(*args)
-                if isinstance(out, tuple):
-                    out = out[0]    # fused ops write exactly one payload
-                return out
-
-            return jax.jit(_named(stacked_call, "bind_bucket", fn))
-
-        return self._resolve(key, build)
+        return self._resolve(key, lambda: bucket_call(fn, layout, n_batch))
 
     def lookup_chain(self, fn: Callable, layout: tuple, n_batch: int,
                      n_levels: int, carry_pos: int, sig_args) -> Callable:
@@ -312,6 +324,33 @@ class ExecutableCache:
             return out
 
         return first_call
+
+
+def bucket_call(fn: Callable, layout: tuple, n_batch: int) -> Callable:
+    """Build the jitted ``vmap(fn)`` over ``n_batch`` fused ops whose flat
+    arguments follow ``layout`` (see :meth:`ExecutableCache.lookup_vmapped`);
+    it returns the stacked result."""
+    in_axes = tuple(None if lay == "const" else 0 for lay in layout)
+
+    def stacked_call(*flat):
+        args = []
+        pos = 0
+        for lay in layout:
+            if lay == "flat":
+                args.append(jax.numpy.stack(flat[pos:pos + n_batch]))
+                pos += n_batch
+            elif type(lay) is tuple:    # ("rows", n_buffers, n_payloads)
+                column, pos = _read_rows(lay[1], lay[2], flat, pos)
+                args.append(column)
+            else:                       # "stacked" buffer or "const"
+                args.append(flat[pos])
+                pos += 1
+        out = jax.vmap(fn, in_axes=in_axes)(*args)
+        if isinstance(out, tuple):
+            out = out[0]                # fused ops write exactly one payload
+        return out
+
+    return jax.jit(_named(stacked_call, "bind_bucket", fn))
 
 
 # VMEM the chain kernel's pipelined blocks may take (double buffers included)

@@ -22,11 +22,12 @@ import jax.numpy as jnp
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from repro.core.executable_cache import chain_pallas_call
+from repro.core.executable_cache import bucket_call, chain_pallas_call
 from repro.core.lowering import SHIP_SCHEDULES, broadcast_by_schedule
 from repro.kernels.flash_attention.ops import attn_step, flash_attention
 from repro.kernels.gemm.ops import gemm_tile, matmul
 from repro.kernels.linear_scan.ops import linear_scan, scan_step
+from repro.linalg.tiles import _t_add, _t_iadd
 
 
 @pytest.fixture(scope="module")
@@ -81,6 +82,37 @@ def test_flash_attention_compiles_4096_bf16(one_chip):
 def test_linear_scan_compiles_4096x1024_f32(one_chip):
     x = jax.ShapeDtypeStruct((4, 4096, 1024), jnp.float32, sharding=one_chip)
     _kernel_compiles(linear_scan, x, x)
+
+
+# a fused bucket whose columns read lazy rows of 1024^2 f32 tiles: Listing
+# 1's reduction level (256 rows of a 512-row buffer per column) and a
+# Strassen add (rows of two buffers interleaved with concrete tiles)
+BUCKET_ROWS = {
+    "one_source": (_t_iadd, 256, ((512, 0), (512, 0))),
+    "two_sources_and_tiles": (_t_add, 120, ((343, 100, 16), (49, 49, 0))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BUCKET_ROWS))
+def test_bucket_reading_rows_compiles_at_1024_f32(one_chip, case):
+    """The rows are copied out one by one: the compiled bucket holds no
+    copy of a whole source buffer, only its stacked columns."""
+    fn, n, columns = BUCKET_ROWS[case]
+
+    def tiles(*lead):
+        return jax.ShapeDtypeStruct(lead + (1024, 1024), jnp.float32,
+                                    sharding=one_chip)
+
+    layout, args = [], []
+    for *buffers, n_tiles in columns:
+        layout.append(("rows", len(buffers), n_tiles))
+        args += [tiles(rows) for rows in buffers] + [tiles()] * n_tiles
+        args.append(jax.ShapeDtypeStruct((n, 2), jnp.int32,
+                                         sharding=one_chip))
+    compiled = bucket_call(fn, tuple(layout), n).lower(*args).compile()
+    column_bytes = n * 1024 * 1024 * 4
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            <= len(columns) * column_bytes + (64 << 20))
 
 
 @pytest.mark.parametrize("schedule", SHIP_SCHEDULES)
