@@ -27,6 +27,7 @@ from repro.core.lowering import SHIP_SCHEDULES, broadcast_by_schedule
 from repro.kernels.flash_attention.ops import attn_step, flash_attention
 from repro.kernels.gemm.ops import gemm_tile, matmul
 from repro.kernels.linear_scan.ops import linear_scan, scan_step
+from repro.linalg.cholesky import _t_potrf, _t_trsm
 from repro.linalg.tiles import _t_add, _t_iadd
 
 
@@ -113,6 +114,25 @@ def test_bucket_reading_rows_compiles_at_1024_f32(one_chip, case):
     column_bytes = n * 1024 * 1024 * 4
     assert (compiled.memory_analysis().temp_size_in_bytes
             <= len(columns) * column_bytes + (64 << 20))
+
+
+# the Cholesky panel at its cell's tiles, 1024^2 f32 at ``highest``: the
+# diagonal tile's factor alone, and the first step's trsm bucket (19 tiles,
+# the diagonal factor stacked with each)
+PANEL = {
+    "potrf": (_t_potrf, None, 1, 1),
+    "trsm": (_t_trsm, ("flat", "flat"), 19, 38),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PANEL))
+def test_cholesky_panel_compiles_at_1024_f32(one_chip, case):
+    fn, layout, n, n_tiles = PANEL[case]
+    tile = jax.ShapeDtypeStruct((1024, 1024), jnp.float32, sharding=one_chip)
+    call = jax.jit(fn) if layout is None else bucket_call(fn, layout, n)
+    with jax.default_matmul_precision("highest"):
+        out = call.lower(*[tile] * n_tiles).compile().memory_analysis()
+    assert out.output_size_in_bytes == n * 1024 * 1024 * 4
 
 
 @pytest.mark.parametrize("schedule", SHIP_SCHEDULES)
